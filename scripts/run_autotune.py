@@ -33,6 +33,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from benchmarks.autotune_bench import PARITY_RTOL, bench_cell
+from repro.compile_cache import enable_compile_cache
 from repro.data.frostt import FROSTT_TENSORS, PAPER_RANK
 from repro.dse.autotune import Autotuner, TuneSpace
 from repro.kernels.mttkrp.ops import resolve_backend
@@ -79,6 +80,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--out", default="BENCH_autotune.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tensors = _parse_tensors(
         args.tensors or (QUICK_TENSORS if args.quick else DEFAULT_TENSORS)

@@ -31,6 +31,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.cp_als import cp_als
 from repro.core.cp_als_fused import FUSED_FIT_TOL, FusedCPALS
 from repro.data.frostt import FROSTT_TENSORS, PAPER_RANK
@@ -101,6 +102,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--out", default="BENCH_cp_als.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tensors = _parse_tensors(
         args.tensors or (QUICK_TENSORS if args.quick else DEFAULT_TENSORS)

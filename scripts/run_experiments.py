@@ -25,6 +25,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.frostt import FROSTT_TENSORS, PAPER_RANK
 from repro.data.synthetic_tensors import EXPERIMENT_SCALES
 from repro.experiments import ExperimentSpec, run_experiments
@@ -105,6 +106,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--out", default="BENCH_experiments.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     impls = tuple(i.strip() for i in args.impls.split(",") if i.strip())
     unknown = [i for i in impls if i not in ("ref", "pallas", "sharded")]

@@ -25,6 +25,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.frostt import PAPER_RANK
 from repro.perf.report import reorder_report_md
 from repro.reorder import ORDERINGS
@@ -47,6 +48,7 @@ def main(argv: list[str] | None = None) -> int:
     )
     ap.add_argument("--out", default="BENCH_reorder.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     strategies = tuple(s.strip() for s in args.strategies.split(",") if s.strip())
     unknown = [s for s in strategies if s not in ORDERINGS]

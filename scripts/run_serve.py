@@ -35,6 +35,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.cp_als import cp_als
 from repro.core.cp_als_fused import FUSED_FIT_TOL
 from repro.serve import (
@@ -98,6 +99,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--quick", action="store_true", help="CI smoke: small traces, 2 repeats")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     # The scaling trace must divide evenly by every batch size: a ragged
     # tail batch is padded to max_batch, and its wasted pad-slot compute

@@ -7,10 +7,10 @@ streaming-accumulation kernel avoids it with the ``first`` predicate:
 ``@pl.when(first)`` zero/initialize-stores, ``@pl.when(not first)``
 accumulates.  The correctness of that idiom hinges on one easily-lost
 detail: the block-boundary test MUST be wrapped with ``t == 0``
-(``jnp.logical_or(t == 0, blk != tile_block_ref[t - 1])``), because at
-``t == 0`` the ``t - 1`` look-behind wraps to the LAST tile and the
-boundary test alone may evaluate false — leaving block 0's scratch
-uninitialized.
+(``jnp.logical_or(t == 0, blk != tile_block_ref[jnp.maximum(t - 1, 0)])``),
+because at ``t == 0`` the ``t - 1`` look-behind is clamped to tile 0 (or,
+unclamped, wraps to the LAST tile) and the boundary test alone may
+evaluate false — leaving block 0's scratch uninitialized.
 
 This pass proves the write-before-read property statically from the
 symbolic traffic interpreter's predicated access sites (textual order is
@@ -80,7 +80,8 @@ class GridCarryInit(Checker):
                             f"{s.fn}: store to scratch {s.ref!r} is guarded "
                             "by a block-boundary test without the t==0 wrap "
                             "guard — at grid step 0 the t-1 look-behind "
-                            "wraps and block 0's scratch stays uninitialized",
+                            "cannot see a boundary and block 0's scratch "
+                            "stays uninitialized",
                         )
                     continue
                 # load or rmw — a read of grid-carried scratch

@@ -8,7 +8,10 @@ it:
 
   * **plan residency** — every per-mode ``MTTKRPPlan`` (pallas) /
     ``ShardedModeSetup`` (sharded) / ordered COO view (ref) is built once
-    at construction and lives on device for all sweeps and restarts;
+    at construction and lives on device for all sweeps and restarts.
+    The jitted sweep takes these device buffers as ARGUMENTS: captured
+    arrays would be embedded in the program as constants, making the
+    executable — and its compile-cache key — as large as the tensor;
   * **fused sweeps** — an entire ALS sweep (all modes' MTTKRP +
     Hadamard-of-Grams solve + column normalization) plus the in-graph fit
     runs as one jitted ``lax.scan`` over iterations.  The per-mode update
@@ -140,29 +143,28 @@ class FusedCPALS:
         from repro.kernels.mttkrp.ops import tensor_device_operands
 
         ops = tensor_device_operands(tensor, dtype=compute_dtype)
-        self._indices = ops.indices
-        self._values = ops.values
-        self._norm2 = ops.norm2
+        fit_operands = (ops.norm2, ops.indices, ops.values)
         self._sweep_cache: dict[tuple[int, bool], callable] = {}
 
         if impl == "ref":
             # Per-mode ordered COO views when a strategy is requested
             # (repro.reorder, DESIGN.md §10); one shared view otherwise.
-            self._ref_streams: dict[int, tuple[jax.Array, jax.Array]] = {}
             if ordering is not None:
                 from repro.reorder import nonzero_order
 
+                mode_operands = []
                 for m in range(self.nmodes):
                     o = nonzero_order(
                         tensor, m, ordering, rows_per_block=rows_per_block
                     )
-                    self._ref_streams[m] = (
-                        jnp.asarray(tensor.indices[o]),
-                        jnp.asarray(tensor.values[o]).astype(compute_dtype),
+                    mode_operands.append(
+                        (
+                            jnp.asarray(tensor.indices[o]),
+                            jnp.asarray(tensor.values[o]).astype(compute_dtype),
+                        )
                     )
             else:
-                shared = (self._indices, self._values)
-                self._ref_streams = {m: shared for m in range(self.nmodes)}
+                mode_operands = [(ops.indices, ops.values)] * self.nmodes
         elif impl == "pallas":
             from repro.kernels.mttkrp.ops import (
                 get_plan,
@@ -182,60 +184,69 @@ class FusedCPALS:
                 for m in range(self.nmodes)
             ]
             # Upload once; every sweep of every restart reuses the buffers.
-            for p in self._plans:
-                plan_device_buffers(p)
+            mode_operands = [plan_device_buffers(p) for p in self._plans]
         else:  # sharded
-            from repro.distributed.mttkrp_dist import build_sharded_mode_setup
+            from repro.distributed.mttkrp_dist import (
+                build_sharded_mode_setup,
+                data_mesh,
+            )
 
             self._axis = "data"
-            self._mesh = jax.make_mesh((jax.device_count(),), (self._axis,))
-            n = self._mesh.shape[self._axis]
-            self._setups = [
+            self._mesh = data_mesh(self._axis)
+            mode_operands = [
                 build_sharded_mode_setup(
                     tensor,
                     m,
-                    n,
+                    self._mesh,
+                    axis=self._axis,
                     scheme=scheme,
                     ordering=ordering,
                     rows_per_block=rows_per_block,
                 )
                 for m in range(self.nmodes)
             ]
+        # Every device buffer a sweep reads, passed to it as one pytree
+        # argument (module docstring, plan residency).
+        self.operands = (tuple(mode_operands), fit_operands)
 
     # -- device-side MTTKRP dispatch (called inside the jitted sweep) -------
 
-    def _mttkrp(self, factors: Sequence[jax.Array], mode: int) -> jax.Array:
+    def _mttkrp(self, factors: Sequence[jax.Array], mode: int, operand) -> jax.Array:
         if self.impl == "ref":
-            idx_m, val_m = self._ref_streams[mode]
+            idx_m, val_m = operand
             return mttkrp_ref((idx_m, val_m, self.tensor.shape), factors, mode)
         if self.impl == "pallas":
             from repro.kernels.mttkrp.ops import mttkrp_from_plan
 
             return mttkrp_from_plan(
-                self._plans[mode], factors, backend=self._backend
+                self._plans[mode], factors, backend=self._backend, bufs=operand
             )
         from repro.distributed.mttkrp_dist import mttkrp_sharded_apply
 
         return mttkrp_sharded_apply(
-            self._setups[mode], factors, mesh=self._mesh, axis=self._axis
+            operand, factors, mesh=self._mesh, axis=self._axis
         )
 
     # -- fused sweep blocks --------------------------------------------------
 
-    def _sweep_fn(self, length: int, batched: bool):
-        """Jitted ``length``-sweep block; cached per (length, batched)."""
+    def sweep_fn(self, length: int, batched: bool):
+        """Jitted ``length``-sweep block ``(operands, factors, weights) ->
+        (factors, weights, fits)``, called with ``self.operands``; cached
+        per (length, batched)."""
         key = (length, batched)
         fn = self._sweep_cache.get(key)
         if fn is not None:
             return fn
 
-        def sweep(factors, weights):
+        def sweep(operands, factors, weights):
+            mode_operands, fit_operands = operands
+
             def body(carry, _):
                 factors, weights = carry
                 for mode in range(self.nmodes):  # unrolled at trace time
-                    m = self._mttkrp(factors, mode)
+                    m = self._mttkrp(factors, mode, mode_operands[mode])
                     factors, weights = _mode_update(factors, weights, m, mode)
-                fit = _fit(self._norm2, self._indices, self._values, factors, weights)
+                fit = _fit(*fit_operands, factors, weights)
                 return (factors, weights), fit
 
             (factors, weights), fits = lax.scan(
@@ -244,7 +255,7 @@ class FusedCPALS:
             return factors, weights, fits
 
         if batched:
-            sweep = jax.vmap(sweep)
+            sweep = jax.vmap(sweep, in_axes=(None, 0, 0))
         fn = jax.jit(sweep)
         self._sweep_cache[key] = fn
         return fn
@@ -304,7 +315,9 @@ class FusedCPALS:
         converged = False
         while it < n_iters and not converged:
             block = min(fit_every, n_iters - it)
-            factors, weights, fits = self._sweep_fn(block, batched)(factors, weights)
+            factors, weights, fits = self.sweep_fn(block, batched)(
+                self.operands, factors, weights
+            )
             # The ONLY device→host sync of the block.
             block_fits = np.asarray(jax.block_until_ready(fits), dtype=np.float64)
             syncs += 1
@@ -379,10 +392,10 @@ def _multi_tensor_sweep(shape: tuple[int, ...], length: int):
 class MultiTensorCPALS:
     """Fused CP-ALS over a batch of DISTINCT tensors with one geometry.
 
-    ``FusedCPALS`` batches restarts of ONE tensor (operands are captured
-    constants); this executor batches *different* tensors that share a
-    padded geometry — the multi-tenant serving case (repro.serve,
-    DESIGN.md §12).  All tensors in a batch must be padded to the same
+    ``FusedCPALS`` batches restarts of ONE tensor (its operands are
+    shared, unbatched arguments); this executor batches *different*
+    tensors that share a padded geometry — the multi-tenant serving case
+    (repro.serve, DESIGN.md §12).  All tensors in a batch must be padded to the same
     ``(shape, nnz_pad)`` and their factors to the same rank; zero-row /
     zero-column / zero-value padding is exactly result-preserving (the
     parity argument is spelled out in DESIGN.md §12 and enforced by

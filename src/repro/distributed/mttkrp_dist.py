@@ -19,18 +19,19 @@ Two schemes, mirroring DESIGN.md §2's changed-assumptions note:
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.sparse_tensor import SparseTensor
 
 __all__ = [
     "ShardedModeSetup",
     "build_sharded_mode_setup",
+    "data_mesh",
     "mttkrp_sharded",
     "mttkrp_sharded_apply",
     "partition_by_output_rows",
@@ -101,15 +102,33 @@ def partition_by_output_rows(
     return out_idx, out_val, row_start
 
 
+def data_mesh(axis: str = "data") -> Mesh:
+    """1-D mesh over every device, with an ``Auto`` axis.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes, under which slicing
+    the row-range-sharded output back to ``I_mode`` rows is a sharding
+    type error; ``Auto`` lets the compiler reassemble it.
+    """
+    return jax.make_mesh((jax.device_count(),), (axis,), axis_types=(AxisType.Auto,))
+
+
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["idx", "val", "row_start", "leftover_idx", "leftover_val"],
+    meta_fields=["mode", "scheme", "nmodes", "i_out", "n_shards", "rows_per"],
+)
 @dataclasses.dataclass(frozen=True)
 class ShardedModeSetup:
     """Host-precomputed, device-resident buffers for one (mode, scheme).
 
     The O(nnz log nnz) partitioning work of the sharded path, split off
     from the per-call math so callers that run many MTTKRPs per mode —
-    the fused CP-ALS executor (DESIGN.md §11) — pay it once.  All arrays
-    are device-resident; ``mttkrp_sharded_apply`` is pure jax and legal
-    inside a jit trace (including under ``lax.scan`` / ``vmap``).
+    the fused CP-ALS executor (DESIGN.md §11) — pay it once.  The
+    partitioned arrays are sharded over the mesh's data axis (the
+    leftovers are replicated); ``mttkrp_sharded_apply`` is pure jax and
+    legal inside a jit trace (including under ``lax.scan`` / ``vmap``).
+    The setup is a pytree whose arrays are its leaves, so a jitted
+    caller can take it as an argument instead of capturing constants.
 
     ``leftover_idx``/``leftover_val`` hold the nonzeros masked out of the
     equal-height shard blocks (the block-vs-nnz boundary mismatch); None
@@ -132,14 +151,21 @@ class ShardedModeSetup:
 def build_sharded_mode_setup(
     tensor: SparseTensor,
     mode: int,
-    n_shards: int,
+    mesh: Mesh,
     *,
+    axis: str = "data",
     scheme: str = "mode_ordered",
     ordering: str | None = None,
     rows_per_block: int = 256,
 ) -> ShardedModeSetup:
-    """Partition ``tensor`` for ``mode`` once; see ``mttkrp_sharded``."""
+    """Partition ``tensor`` for ``mode`` over ``mesh``'s ``axis`` once;
+    see ``mttkrp_sharded``."""
     i_out = tensor.shape[mode]
+    n_shards = mesh.shape[axis]
+
+    def put(x: np.ndarray, *spec) -> jax.Array:
+        return jax.device_put(x, NamedSharding(mesh, P(*spec)))
+
     ord_perm = None
     if ordering is not None:
         from repro.reorder import nonzero_order
@@ -161,8 +187,8 @@ def build_sharded_mode_setup(
             i_out=i_out,
             n_shards=n_shards,
             rows_per=per,
-            idx=jnp.asarray(idx),
-            val=jnp.asarray(val),
+            idx=put(idx, axis, None),
+            val=put(val, axis),
             row_start=None,
             leftover_idx=None,
             leftover_val=None,
@@ -184,8 +210,8 @@ def build_sharded_mode_setup(
     leftover = ~owned & (val_s != 0)
     leftover_idx = leftover_val = None
     if leftover.any():
-        leftover_idx = jnp.asarray(idx_s[leftover])
-        leftover_val = jnp.asarray(val_s[leftover].astype(np.float32))
+        leftover_idx = put(idx_s[leftover])
+        leftover_val = put(val_s[leftover].astype(np.float32))
     return ShardedModeSetup(
         mode=mode,
         scheme=scheme,
@@ -193,9 +219,9 @@ def build_sharded_mode_setup(
         i_out=i_out,
         n_shards=n_shards,
         rows_per=rows_per,
-        idx=jnp.asarray(idx_s),
-        val=jnp.asarray(val_s),
-        row_start=jnp.asarray(row_start),
+        idx=put(idx_s, axis, None, None),
+        val=put(val_s, axis, None),
+        row_start=put(row_start, axis),
         leftover_idx=leftover_idx,
         leftover_val=leftover_val,
     )
@@ -224,12 +250,12 @@ def mttkrp_sharded_apply(
             out = jax.ops.segment_sum(acc, idx_l[:, mode], num_segments=i_out)
             return jax.lax.psum(out, axis)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(P(axis, None), P(axis)) + (P(None, None),) * len(facs),
             out_specs=P(None, None),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(setup.idx, setup.val, *facs)[:i_out].astype(facs[mode].dtype)
 
@@ -257,12 +283,12 @@ def mttkrp_sharded_apply(
     # outside the shard's block are masked (they belong to a neighbor's
     # block boundary, from the even-nnz snapping) — correctness is
     # preserved by the tiny residual pass below.
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None), P(axis)) + (P(None, None),) * len(facs),
         out_specs=P(axis, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     out = fn(setup.idx, setup.val, setup.row_start, *facs)
     out = out.reshape(setup.n_shards * rows_per, rank)[:i_out]
@@ -308,11 +334,12 @@ def mttkrp_sharded(
     — the fused CP-ALS executor does (DESIGN.md §11).
     """
     if mesh is None:
-        mesh = jax.make_mesh((jax.device_count(),), (axis,))
+        mesh = data_mesh(axis)
     setup = build_sharded_mode_setup(
         tensor,
         mode,
-        mesh.shape[axis],
+        mesh,
+        axis=axis,
         scheme=scheme,
         ordering=ordering,
         rows_per_block=rows_per_block,

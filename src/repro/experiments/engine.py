@@ -8,9 +8,10 @@ both on the SAME workload and reconciles them (DESIGN.md §7):
   1. materialize every requested FROSTT spec at a configurable scale
      (``repro.data.synthetic_tensors``);
   2. execute full CP-ALS sweeps through each impl — ``ref`` and ``pallas``
-     in-process, ``sharded`` in a subprocess with
+     in-process, ``sharded`` on CPU in a subprocess with
      ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (XLA pins the
-     device count at first init) — collecting per-mode wall time, HLO
+     device count at first init) and on an accelerator in-process over
+     ``jax.devices()`` — collecting per-mode wall time, HLO
      ``cost_analysis`` FLOPs/bytes, and exact LRU hit rates over the
      impl's executed nonzero order (``repro.experiments.measure``);
   3. price the same runs on all four memory stacks — E-SRAM, O-SRAM,
@@ -315,7 +316,18 @@ def _measure(
     rows_per_block: int = 256,
 ):
     if impl == "sharded":
-        return _measure_sharded_subprocess(spec, name, scale, ft.name, ordering)
+        import jax
+
+        if jax.default_backend() == "cpu":
+            return _measure_sharded_subprocess(spec, name, scale, ft.name, ordering)
+        # An accelerator belongs to the process that initialized JAX (this
+        # one): a child would fail or hang on it, so measure here over the
+        # real devices.
+        if jax.device_count() != spec.n_shards:
+            raise ValueError(
+                f"sharded leg needs n_shards={spec.n_shards} devices; this "
+                f"{jax.default_backend()} host has {jax.device_count()}"
+            )
     return measure_cp_als(
         tensor,
         name=ft.name,
@@ -327,9 +339,12 @@ def _measure(
         rows_per_block=rows_per_block,
         ordering=ordering,
         backend=spec.backend,
-        cost_analysis=spec.cost_analysis,
+        # The sharded shard_map path has no single compiled HLO to analyze
+        # (as in repro.experiments.worker).
+        cost_analysis=spec.cost_analysis and impl != "sharded",
         fused=spec.fused,
         fit_every=spec.fit_every,
+        scheme=spec.scheme,
     )
 
 

@@ -31,12 +31,15 @@ is exactly ``(I_mode, rank)``.
 from __future__ import annotations
 
 import functools
-from typing import Sequence
+from typing import TYPE_CHECKING, Sequence
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.sparse_tensor import MTTKRPPlan
+
+if TYPE_CHECKING:
+    from repro.kernels.mttkrp.ops import PlanBuffers
 
 __all__ = ["DEFAULT_NNZ_CHUNK", "mttkrp_xla_call", "mttkrp_xla_from_plan"]
 
@@ -93,6 +96,7 @@ def mttkrp_xla_call(
 def mttkrp_xla_from_plan(
     plan: MTTKRPPlan,
     factors: Sequence[jax.Array],
+    bufs: PlanBuffers,
     *,
     nnz_chunk: int = DEFAULT_NNZ_CHUNK,
 ) -> jax.Array:
@@ -102,13 +106,10 @@ def mttkrp_xla_from_plan(
     ``ops.mttkrp_pallas_from_plan``, from the same device-resident plan
     buffers (so a plan already warmed for the Pallas path re-stages
     nothing when the dispatch layer picks this backend instead).
+    ``bufs`` are those buffers (``ops.plan_device_buffers``, or jit
+    arguments of a traced caller).
     """
-    # Local import: ops is the dispatch layer that calls back into this
-    # module, so the buffer memo is fetched at call time.
-    from repro.kernels.mttkrp.ops import plan_device_buffers
-
     mode = plan.mode
-    bufs = plan_device_buffers(plan)
     other = [k for k in range(len(factors)) if k != mode]
     gathered = jnp.stack(
         [jnp.take(factors[k], bufs.indices[:, k], axis=0) for k in other]

@@ -47,9 +47,10 @@ def _kernel(
     t = pl.program_id(0)
     num_tiles = pl.num_programs(0)
     blk = tile_block_ref[t]
-    # t==0 short-circuits the (wrapping) t-1 load — the first tile always
-    # initializes, even when the wrapped last tile shares its block.
-    first = jnp.logical_or(t == 0, blk != tile_block_ref[t - 1])
+    # ``logical_or`` evaluates both sides, so the t-1 load is clamped to
+    # stay inside SMEM; at t==0 it then reads tile 0 itself, and only the
+    # t==0 term makes the first tile initialize.
+    first = jnp.logical_or(t == 0, blk != tile_block_ref[jnp.maximum(t - 1, 0)])
     # Last tile of this output block; the t+1 load is clamped so the final
     # tile (flushed unconditionally) never indexes past the grid.
     last = jnp.logical_or(
@@ -61,13 +62,24 @@ def _kernel(
     prod = fac_ref[0].astype(acc_t)
     for k in range(1, nfac):
         prod = prod * fac_ref[k].astype(acc_t)
-    prod = prod * vals_ref[...].astype(acc_t)[:, None]
 
+    # values/local_row arrive as (1, tile_nnz) lane-major rows, so the
+    # value scaling is folded into the one-hot along its nonzero axis
+    # instead of being transposed onto prod's sublanes.
     rows_per_block = out_ref.shape[0]
     tile_nnz = prod.shape[0]
     row_iota = jax.lax.broadcasted_iota(jnp.int32, (rows_per_block, tile_nnz), 0)
-    onehot = (row_iota == local_ref[...][None, :]).astype(acc_t)
-    contrib = jnp.dot(onehot, prod, preferred_element_type=jnp.float32)
+    scatter = jnp.where(
+        row_iota == local_ref[...], vals_ref[...].astype(acc_t), 0.0
+    )
+    # HIGHEST pins an fp32 contraction instead of leaving the f32 matmul's
+    # precision to Mosaic's default.
+    contrib = jnp.dot(
+        scatter,
+        prod,
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
 
     @pl.when(first)
     def _init():
@@ -125,12 +137,15 @@ def mttkrp_pallas_call(
             f"SUBLANE({SUBLANE})"
         )
 
+    # values/local_row go in as (1, nnz_pad) rows with (1, tile_nnz)
+    # blocks: a 1-D (tile_nnz,) block only matches XLA's T(1024) layout
+    # of a 1-D f32/int32 array when tile_nnz is a multiple of 1024.
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(num_tiles,),
         in_specs=[
-            pl.BlockSpec((tile_nnz,), lambda t, tb: (t,)),
-            pl.BlockSpec((tile_nnz,), lambda t, tb: (t,)),
+            pl.BlockSpec((1, tile_nnz), lambda t, tb: (0, t)),
+            pl.BlockSpec((1, tile_nnz), lambda t, tb: (0, t)),
             pl.BlockSpec((nfac, tile_nnz, r_pad), lambda t, tb: (0, t, 0)),
         ],
         out_specs=pl.BlockSpec((rows_per_block, r_pad), lambda t, tb: (tb[t], 0)),
@@ -138,14 +153,10 @@ def mttkrp_pallas_call(
     )
     out_shape = jax.ShapeDtypeStruct((num_blocks * rows_per_block, r_pad), jnp.float32)
     kernel = functools.partial(_kernel, nfac=nfac)
-    try:
-        compiler_params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
-    except AttributeError:  # older jax spelling
-        compiler_params = pltpu.TPUCompilerParams(dimension_semantics=("arbitrary",))
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
-        compiler_params=compiler_params,
-    )(tile_block, values, local_row, gathered)
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+    )(tile_block, values.reshape(1, nnz_pad), local_row.reshape(1, nnz_pad), gathered)

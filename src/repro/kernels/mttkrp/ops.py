@@ -199,6 +199,7 @@ def mttkrp_from_plan(
     *,
     backend: str | None = None,
     interpret: bool | None = None,
+    bufs: PlanBuffers | None = None,
 ) -> jax.Array:
     """MTTKRP from a plan alone.  Returns (I_mode, R) for ``plan.mode``.
 
@@ -212,18 +213,25 @@ def mttkrp_from_plan(
     ``backend``/``interpret`` pick the execution path via
     :func:`resolve_backend`; the XLA fallback consumes the same plan
     buffers, so switching backends re-stages nothing.
+
+    ``bufs`` replaces the memoized device copies of the plan's arrays: a
+    jitted caller passes them in as arguments so they stay out of the
+    compiled program (the plan then supplies only its static geometry).
     """
     backend = resolve_backend(backend, interpret=interpret)
+    if bufs is None:
+        bufs = plan_device_buffers(plan)
     if backend == "xla":
         from repro.kernels.mttkrp.compiled import mttkrp_xla_from_plan
 
-        return mttkrp_xla_from_plan(plan, factors)
-    return _mttkrp_pallas_exec(plan, factors, interpret=backend == "interpret")
+        return mttkrp_xla_from_plan(plan, factors, bufs)
+    return _mttkrp_pallas_exec(plan, factors, bufs, interpret=backend == "interpret")
 
 
 def _mttkrp_pallas_exec(
     plan: MTTKRPPlan,
     factors: Sequence[jax.Array],
+    bufs: PlanBuffers,
     *,
     interpret: bool,
 ) -> jax.Array:
@@ -231,7 +239,6 @@ def _mttkrp_pallas_exec(
     mode = plan.mode
     rank = factors[0].shape[1]
     r_pad = -(-rank // LANE) * LANE
-    bufs = plan_device_buffers(plan)
 
     other = [k for k in range(len(factors)) if k != mode]
     gathered = jnp.stack(

@@ -1,0 +1,114 @@
+"""Ahead-of-time compiles of the main path for a described TPU v5e.
+
+Nothing here runs on a chip: the TPU compiler compiles for a topology that
+is described, not attached, and raises what the chip's compiler would
+raise (a block shape whose layout Mosaic refuses, VMEM or HBM overuse).
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process at a time may load the TPU compiler
+library, and every pytest-xdist worker imports every test file.  Keep
+these tests in this one file, so one worker owns the library.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.cp_als_fused import FusedCPALS
+from repro.core.sparse_tensor import random_sparse_tensor
+from repro.data.frostt import FROSTT_TENSORS, PAPER_RANK
+from repro.dse.autotune import TuneSpace
+from repro.kernels.mttkrp.kernel import LANE, mttkrp_pallas_call
+from repro.kernels.mttkrp.ops import PlanBuffers, get_plan
+
+# The chip smoke test's single job (chip_smoke.py): NELL-2 cut to 3.9 M nnz.
+NELL2 = FROSTT_TENSORS["NELL-2"]
+SMOKE_NNZ = 3_906_850
+SMOKE_TILES = 15_360  # the smoke run's plans have 15,280-15,318 tiles per mode
+RANK = PAPER_RANK
+V5E_HBM_BYTES = 16 * 10**9
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described ``v5e:2x2``, with the persistent
+    compilation cache off: an entry written for a described chip cannot
+    be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler library in this environment
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize(
+    "nmodes,tile_nnz,num_tiles",
+    [(3, t, 64) for t in TuneSpace().tile_nnz]  # every tile size the autotuner tries
+    + [(5, 256, 64), (3, 256, SMOKE_TILES)],
+)
+def test_mosaic_kernel_compiles_for_v5e(one_chip, nmodes, tile_nnz, num_tiles):
+    nnz_pad = num_tiles * tile_nnz
+    r_pad = -(-RANK // LANE) * LANE
+    compiled = mttkrp_pallas_call.lower(
+        _spec((num_tiles,), jnp.int32, one_chip),
+        _spec((nnz_pad,), jnp.float32, one_chip),
+        _spec((nnz_pad,), jnp.int32, one_chip),
+        _spec((nmodes - 1, nnz_pad, r_pad), jnp.float32, one_chip),
+        tile_nnz=tile_nnz,
+        rows_per_block=256,
+        num_blocks=48,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_sweep_compiles_for_v5e_at_smoke_size(one_chip):
+    """The chip smoke test's fused sweep, with full-size operands as
+    arguments: a Mosaic kernel is in it, no tensor data is embedded in
+    the program, and it fits one chip's HBM."""
+    tensor = random_sparse_tensor(NELL2.dims, nnz=20_000, seed=0, zipf_a=NELL2.zipf_alpha)
+    executor = FusedCPALS(tensor, RANK, impl="pallas", backend="mosaic")
+    mode_specs = []
+    for plan in (get_plan(tensor, m) for m in range(tensor.nmodes)):
+        # a block pads its nonzeros by less than one tile
+        nnz_pad = -(-(SMOKE_NNZ + plan.num_blocks * plan.tile_nnz) // plan.tile_nnz)
+        nnz_pad *= plan.tile_nnz
+        mode_specs.append(
+            PlanBuffers(
+                indices=_spec((nnz_pad, 3), jnp.int32, one_chip),
+                values=_spec((nnz_pad,), jnp.float32, one_chip),
+                local_row=_spec((nnz_pad,), jnp.int32, one_chip),
+                tile_block=_spec((nnz_pad // plan.tile_nnz,), jnp.int32, one_chip),
+            )
+        )
+    fit_specs = (
+        _spec((), jnp.float32, one_chip),
+        _spec((SMOKE_NNZ, 3), jnp.int32, one_chip),
+        _spec((SMOKE_NNZ,), jnp.float32, one_chip),
+    )
+    factors = tuple(_spec((d, RANK), jnp.float32, one_chip) for d in NELL2.dims)
+    weights = _spec((RANK,), jnp.float32, one_chip)
+    lowered = executor.sweep_fn(1, False).lower(
+        (tuple(mode_specs), fit_specs), factors, weights
+    )
+    assert len(lowered.as_text()) < 1 << 20
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES

@@ -164,8 +164,8 @@ def _mini_repo(tmp_path: Path, kernel_text: str, with_ops: bool = True) -> Path:
 def test_mutation_deleted_wrap_guard_is_caught(tmp_path):
     src = (REPO / KERNEL).read_text()
     broken = src.replace(
-        "jnp.logical_or(t == 0, blk != tile_block_ref[t - 1])",
-        "blk != tile_block_ref[t - 1]",
+        "jnp.logical_or(t == 0, blk != tile_block_ref[jnp.maximum(t - 1, 0)])",
+        "blk != tile_block_ref[jnp.maximum(t - 1, 0)]",
     )
     assert broken != src
     root = _mini_repo(tmp_path, broken, with_ops=False)
