@@ -65,20 +65,21 @@ def reconstruct_values(
 
 
 def _fit(tensor_norm2, indices, values, factors, weights) -> jax.Array:
-    grams = [f.T @ f for f in factors]
-    had = grams[0]
-    for g in grams[1:]:
-        had = had * g
-    xhat_norm2 = weights @ had @ weights
-    inner = values @ reconstruct_values(indices, factors, weights)
-    resid2 = jnp.maximum(tensor_norm2 - 2.0 * inner + xhat_norm2, 0.0)
-    # An all-zero tensor has ||X|| = 0; the historical sqrt(0)/sqrt(0)
-    # produced a NaN fit that silently poisoned the convergence check.
-    # Both `where` branches are evaluated, so the denominator must stay
-    # nonzero on the dead branch.
-    safe_norm2 = jnp.where(tensor_norm2 > 0.0, tensor_norm2, 1.0)
-    fit = 1.0 - jnp.sqrt(resid2) / jnp.sqrt(safe_norm2)
-    return jnp.where(tensor_norm2 > 0.0, fit, 0.0)
+    with jax.named_scope("als_fit"):  # names the fit's device ops in a trace
+        grams = [f.T @ f for f in factors]
+        had = grams[0]
+        for g in grams[1:]:
+            had = had * g
+        xhat_norm2 = weights @ had @ weights
+        inner = values @ reconstruct_values(indices, factors, weights)
+        resid2 = jnp.maximum(tensor_norm2 - 2.0 * inner + xhat_norm2, 0.0)
+        # An all-zero tensor has ||X|| = 0; the historical sqrt(0)/sqrt(0)
+        # produced a NaN fit that silently poisoned the convergence check.
+        # Both `where` branches are evaluated, so the denominator must stay
+        # nonzero on the dead branch.
+        safe_norm2 = jnp.where(tensor_norm2 > 0.0, tensor_norm2, 1.0)
+        fit = 1.0 - jnp.sqrt(resid2) / jnp.sqrt(safe_norm2)
+        return jnp.where(tensor_norm2 > 0.0, fit, 0.0)
 
 
 def _mode_update(
@@ -96,22 +97,23 @@ def _mode_update(
     business accumulating normal equations; fp32 inputs are bit-for-bit
     unchanged by the promotion.
     """
-    rank = m.shape[1]
-    solve_dtype = jnp.promote_types(m.dtype, jnp.float32)
-    had = jnp.ones((rank, rank), solve_dtype)
-    for k in range(len(factors)):
-        if k != mode:
-            fk = factors[k].astype(solve_dtype)
-            had = had * (fk.T @ fk)
-    # Solve A_mode @ had = m  (had is SPD up to rank deficiency).
-    a_new = jnp.linalg.solve(
-        had + 1e-8 * jnp.eye(rank, dtype=solve_dtype), m.T.astype(solve_dtype)
-    ).T
-    # Column normalization -> weights (standard CP-ALS lambda).
-    norms = jnp.maximum(jnp.linalg.norm(a_new, axis=0), 1e-12)
-    out = list(factors)
-    out[mode] = (a_new / norms).astype(factors[mode].dtype)
-    return tuple(out), norms.astype(weights.dtype)
+    with jax.named_scope("als_update"):  # names the update's device ops in a trace
+        rank = m.shape[1]
+        solve_dtype = jnp.promote_types(m.dtype, jnp.float32)
+        had = jnp.ones((rank, rank), solve_dtype)
+        for k in range(len(factors)):
+            if k != mode:
+                fk = factors[k].astype(solve_dtype)
+                had = had * (fk.T @ fk)
+        # Solve A_mode @ had = m  (had is SPD up to rank deficiency).
+        a_new = jnp.linalg.solve(
+            had + 1e-8 * jnp.eye(rank, dtype=solve_dtype), m.T.astype(solve_dtype)
+        ).T
+        # Column normalization -> weights (standard CP-ALS lambda).
+        norms = jnp.maximum(jnp.linalg.norm(a_new, axis=0), 1e-12)
+        out = list(factors)
+        out[mode] = (a_new / norms).astype(factors[mode].dtype)
+        return tuple(out), norms.astype(weights.dtype)
 
 
 def cp_als(

@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.profiler import TraceAnnotation
 
 from repro.core.cp_als import CPState, _fit, _mode_update, cp_init
 from repro.core.mttkrp import mttkrp_ref
@@ -212,20 +213,21 @@ class FusedCPALS:
     # -- device-side MTTKRP dispatch (called inside the jitted sweep) -------
 
     def _mttkrp(self, factors: Sequence[jax.Array], mode: int, operand) -> jax.Array:
-        if self.impl == "ref":
-            idx_m, val_m = operand
-            return mttkrp_ref((idx_m, val_m, self.tensor.shape), factors, mode)
-        if self.impl == "pallas":
-            from repro.kernels.mttkrp.ops import mttkrp_from_plan
+        with jax.named_scope("mttkrp"):  # every impl, unpad and cast included
+            if self.impl == "ref":
+                idx_m, val_m = operand
+                return mttkrp_ref((idx_m, val_m, self.tensor.shape), factors, mode)
+            if self.impl == "pallas":
+                from repro.kernels.mttkrp.ops import mttkrp_from_plan
 
-            return mttkrp_from_plan(
-                self._plans[mode], factors, backend=self._backend, bufs=operand
+                return mttkrp_from_plan(
+                    self._plans[mode], factors, backend=self._backend, bufs=operand
+                )
+            from repro.distributed.mttkrp_dist import mttkrp_sharded_apply
+
+            return mttkrp_sharded_apply(
+                operand, factors, mesh=self._mesh, axis=self._axis
             )
-        from repro.distributed.mttkrp_dist import mttkrp_sharded_apply
-
-        return mttkrp_sharded_apply(
-            operand, factors, mesh=self._mesh, axis=self._axis
-        )
 
     # -- fused sweep blocks --------------------------------------------------
 
@@ -296,66 +298,81 @@ class FusedCPALS:
         seeds = tuple(int(s) for s in seeds)
         batched = len(seeds) > 1
 
-        inits = [
-            cp_init(self.tensor, self.rank, seed=s, dtype=self.dtype) for s in seeds
-        ]
-        if batched:
-            factors = tuple(
-                jnp.stack([init[k] for init in inits]) for k in range(self.nmodes)
-            )
-            weights = jnp.ones((len(seeds), self.rank), factors[0].dtype)
-        else:
-            factors = tuple(inits[0])
-            weights = jnp.ones((self.rank,), factors[0].dtype)
+        with TraceAnnotation(
+            "cp_als.run", n_iters=n_iters, fit_every=fit_every, restarts=len(seeds)
+        ):
+            with TraceAnnotation("cp_als.init"):
+                inits = [
+                    cp_init(self.tensor, self.rank, seed=s, dtype=self.dtype)
+                    for s in seeds
+                ]
+                if batched:
+                    factors = tuple(
+                        jnp.stack([init[k] for init in inits])
+                        for k in range(self.nmodes)
+                    )
+                    weights = jnp.ones((len(seeds), self.rank), factors[0].dtype)
+                else:
+                    factors = tuple(inits[0])
+                    weights = jnp.ones((self.rank,), factors[0].dtype)
 
-        fit_cols: list[np.ndarray] = []  # one (restarts,) column per iteration
-        fit_prev = np.full((len(seeds),), -np.inf)
-        it = 0
-        syncs = 0
-        converged = False
-        while it < n_iters and not converged:
-            block = min(fit_every, n_iters - it)
-            factors, weights, fits = self.sweep_fn(block, batched)(
-                self.operands, factors, weights
-            )
-            # The ONLY device→host sync of the block.
-            block_fits = np.asarray(jax.block_until_ready(fits), dtype=np.float64)
-            syncs += 1
-            cols = block_fits if batched else block_fits[None, :]  # (R, block)
-            for j in range(cols.shape[1]):
-                it += 1
-                fit_cols.append(cols[:, j])
-                if verbose:
-                    shown = ", ".join(f"{f:.6f}" for f in cols[:, j])
-                    print(f"  fused ALS iter {it:3d}  fit=[{shown}]")
-                if np.all(np.abs(cols[:, j] - fit_prev) < tol):
-                    converged = True
-                    fit_prev = cols[:, j]
-                    break
-                fit_prev = cols[:, j]
+            fit_cols: list[np.ndarray] = []  # one (restarts,) column per iteration
+            fit_prev = np.full((len(seeds),), -np.inf)
+            it = 0
+            syncs = 0
+            converged = False
+            while it < n_iters and not converged:
+                block = min(fit_every, n_iters - it)
+                # ``new_program`` is 1 on the block that builds the program
+                # for its length: a count of programs built, read off the trace.
+                new_program = int((block, batched) not in self._sweep_cache)
+                with TraceAnnotation(
+                    "cp_als.block", sweeps=block, new_program=new_program
+                ):
+                    factors, weights, fits = self.sweep_fn(block, batched)(
+                        self.operands, factors, weights
+                    )
+                with TraceAnnotation("cp_als.fit_sync"):
+                    # The ONLY device→host sync of the block.
+                    block_fits = np.asarray(
+                        jax.block_until_ready(fits), dtype=np.float64
+                    )
+                    syncs += 1
+                    cols = block_fits if batched else block_fits[None, :]  # (R, block)
+                    for j in range(cols.shape[1]):
+                        it += 1
+                        fit_cols.append(cols[:, j])
+                        if verbose:
+                            shown = ", ".join(f"{f:.6f}" for f in cols[:, j])
+                            print(f"  fused ALS iter {it:3d}  fit=[{shown}]")
+                        if np.all(np.abs(cols[:, j] - fit_prev) < tol):
+                            converged = True
+                            fit_prev = cols[:, j]
+                            break
+                        fit_prev = cols[:, j]
 
-        fits_mat = np.stack(fit_cols, axis=1)  # (restarts, iters)
-        best = int(np.argmax(fits_mat[:, -1]))
-        if batched:
-            best_factors = [f[best] for f in factors]
-            best_weights = weights[best]
-        else:
-            best_factors = list(factors)
-            best_weights = weights
-        state = CPState(
-            factors=best_factors,
-            weights=best_weights,
-            fit=float(fits_mat[best, -1]),
-            fits=[float(f) for f in fits_mat[best]],
-            iters=it,
-        )
-        return BatchedCPState(
-            state=state,
-            best_restart=best,
-            seeds=seeds,
-            fits=fits_mat,
-            sync_count=syncs,
-        )
+            fits_mat = np.stack(fit_cols, axis=1)  # (restarts, iters)
+            best = int(np.argmax(fits_mat[:, -1]))
+            if batched:
+                best_factors = [f[best] for f in factors]
+                best_weights = weights[best]
+            else:
+                best_factors = list(factors)
+                best_weights = weights
+            state = CPState(
+                factors=best_factors,
+                weights=best_weights,
+                fit=float(fits_mat[best, -1]),
+                fits=[float(f) for f in fits_mat[best]],
+                iters=it,
+            )
+            return BatchedCPState(
+                state=state,
+                best_restart=best,
+                seeds=seeds,
+                fits=fits_mat,
+                sync_count=syncs,
+            )
 
 
 @functools.lru_cache(maxsize=128)

@@ -111,14 +111,17 @@ def mttkrp_xla_from_plan(
     """
     mode = plan.mode
     other = [k for k in range(len(factors)) if k != mode]
-    gathered = jnp.stack(
-        [jnp.take(factors[k], bufs.indices[:, k], axis=0) for k in other]
-    )  # (K, nnz_pad, R)
-    out = mttkrp_xla_call(
-        bufs.indices[:, mode],
-        bufs.values,
-        gathered,
-        i_out=plan.shape[mode],
-        nnz_chunk=min(nnz_chunk, int(bufs.values.shape[0])),
-    )
+    # The same scopes as ops._mttkrp_pallas_exec: gather, then kernel.
+    with jax.named_scope("mttkrp_gather"):
+        gathered = jnp.stack(
+            [jnp.take(factors[k], bufs.indices[:, k], axis=0) for k in other]
+        )  # (K, nnz_pad, R)
+    with jax.named_scope("mttkrp_kernel"):
+        out = mttkrp_xla_call(
+            bufs.indices[:, mode],
+            bufs.values,
+            gathered,
+            i_out=plan.shape[mode],
+            nnz_chunk=min(nnz_chunk, int(bufs.values.shape[0])),
+        )
     return out.astype(factors[mode].dtype)

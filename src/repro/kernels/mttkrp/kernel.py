@@ -158,5 +158,6 @@ def mttkrp_pallas_call(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="mttkrp",  # the kernel's name in compiled programs and traces
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(tile_block, values.reshape(1, nnz_pad), local_row.reshape(1, nnz_pad), gathered)

@@ -235,28 +235,35 @@ def _mttkrp_pallas_exec(
     *,
     interpret: bool,
 ) -> jax.Array:
-    """The Pallas leg of the dispatch: gather, kernel call, unpad."""
+    """The Pallas leg of the dispatch: gather, kernel call, unpad.
+
+    The gather and lane pad run under the ``mttkrp_gather`` scope and the
+    kernel under ``mttkrp_kernel``: each device op's ``op_name`` names
+    its part in a profiler trace.
+    """
     mode = plan.mode
     rank = factors[0].shape[1]
     r_pad = -(-rank // LANE) * LANE
 
     other = [k for k in range(len(factors)) if k != mode]
-    gathered = jnp.stack(
-        [jnp.take(factors[k], bufs.indices[:, k], axis=0) for k in other]
-    )  # (K, nnz_pad, R)
-    if r_pad != rank:
-        gathered = jnp.pad(gathered, ((0, 0), (0, 0), (0, r_pad - rank)))
+    with jax.named_scope("mttkrp_gather"):
+        gathered = jnp.stack(
+            [jnp.take(factors[k], bufs.indices[:, k], axis=0) for k in other]
+        )  # (K, nnz_pad, R)
+        if r_pad != rank:
+            gathered = jnp.pad(gathered, ((0, 0), (0, 0), (0, r_pad - rank)))
 
-    out = mttkrp_pallas_call(
-        bufs.tile_block,
-        bufs.values,
-        bufs.local_row,
-        gathered,
-        tile_nnz=plan.tile_nnz,
-        rows_per_block=plan.rows_per_block,
-        num_blocks=plan.num_blocks,
-        interpret=interpret,
-    )
+    with jax.named_scope("mttkrp_kernel"):
+        out = mttkrp_pallas_call(
+            bufs.tile_block,
+            bufs.values,
+            bufs.local_row,
+            gathered,
+            tile_nnz=plan.tile_nnz,
+            rows_per_block=plan.rows_per_block,
+            num_blocks=plan.num_blocks,
+            interpret=interpret,
+        )
     i_out = plan.shape[mode]
     return out[:i_out, :rank].astype(factors[mode].dtype)
 

@@ -12,6 +12,8 @@ these tests in this one file, so one worker owns the library.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -78,10 +80,10 @@ def test_mosaic_kernel_compiles_for_v5e(one_chip, nmodes, tile_nnz, num_tiles):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fused_sweep_compiles_for_v5e_at_smoke_size(one_chip):
-    """The chip smoke test's fused sweep, with full-size operands as
-    arguments: a Mosaic kernel is in it, no tensor data is embedded in
-    the program, and it fits one chip's HBM."""
+@pytest.fixture(scope="module")
+def smoke_sweep(one_chip):
+    """The chip smoke test's fused sweep, lowered and compiled with
+    full-size operands as arguments: ``(lowered, compiled)``."""
     tensor = random_sparse_tensor(NELL2.dims, nnz=20_000, seed=0, zipf_a=NELL2.zipf_alpha)
     executor = FusedCPALS(tensor, RANK, impl="pallas", backend="mosaic")
     mode_specs = []
@@ -107,8 +109,69 @@ def test_fused_sweep_compiles_for_v5e_at_smoke_size(one_chip):
     lowered = executor.sweep_fn(1, False).lower(
         (tuple(mode_specs), fit_specs), factors, weights
     )
+    return lowered, lowered.compile()
+
+
+def test_fused_sweep_compiles_for_v5e_at_smoke_size(smoke_sweep):
+    """A Mosaic kernel is in the sweep, no tensor data is embedded in
+    the program, and it fits one chip's HBM."""
+    lowered, compiled = smoke_sweep
     assert len(lowered.as_text()) < 1 << 20
-    compiled = lowered.compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+# The program's scopes, as each may nest: the MTTKRP dispatch holds the
+# gather and the kernel; the update and the fit stand alone.
+SCOPE_CHAINS = [
+    {"mttkrp"}, {"mttkrp", "mttkrp_gather"}, {"mttkrp", "mttkrp_kernel"},
+    {"als_update"}, {"als_fit"},
+]
+SCOPES = set().union(*SCOPE_CHAINS)
+
+
+NO_DEVICE_OP = {"parameter", "constant", "bitcast", "tuple", "get-tuple-element"}
+
+
+def _entry_ops(hlo_text: str) -> dict[str, tuple[str, str | None]]:
+    """Instruction name -> (its line, its ``op_name`` or None) for the
+    entry computation of compiled HLO text: the ops that run on the
+    device (``NO_DEVICE_OP`` instructions are left out)."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    entry = entry[: entry.index("\n}\n")]
+    ops = {}
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? ([a-z][\w\-]*)\(", line)
+        if m and m.group(2) not in NO_DEVICE_OP:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            ops[m.group(1)] = (line, op_name.group(1) if op_name else None)
+    return ops
+
+
+def _scopes(op_name: str) -> set[str]:
+    return SCOPES & set(re.split(r"[/;]", op_name))
+
+
+def test_fused_sweep_ops_carry_the_program_scopes_on_v5e(smoke_sweep):
+    """Each part of the compiled sweep sits under its scope (the names a
+    profiler trace reads back), and no op of the program's own code
+    falls outside the scopes or between two of them."""
+    _, compiled = smoke_sweep
+    ops = _entry_ops(compiled.as_text())
+    kernels = [(line, name) for line, name in ops.values() if "tpu_custom_call" in line]
+    assert len(kernels) == 3  # one MTTKRP per mode
+    for line, op_name in kernels:
+        assert _scopes(op_name) == {"mttkrp", "mttkrp_kernel"}
+        # the kernel's last operand is the stacked, lane-padded gather
+        gathered = re.search(r"custom-call\((.*?)\)", line).group(1).split(", ")[-1]
+        assert _scopes(ops[gathered.lstrip("%")][1]) == {"mttkrp", "mttkrp_gather"}
+    solve = [name for _, name in ops.values() if name and "jit(solve)" in name]
+    assert solve and all(_scopes(name) == {"als_update"} for name in solve)
+    assert any(name and _scopes(name) == {"als_fit"} for _, name in ops.values())
+    # Ops of the traced program; the others are compiler-inserted (no
+    # op_name) or name an argument they copy (``factors[2]``).
+    program = [name for _, name in ops.values() if name and name.startswith("jit(sweep)/")]
+    assert program
+    for name in program:
+        assert _scopes(name) in SCOPE_CHAINS, name
