@@ -47,6 +47,7 @@ from repro.analysis.core import (
     FunctionInfo,
     SourceFile,
     call_name,
+    reaching_def,
     straightline_defs,
 )
 from repro.analysis.poly import Poly, poly_sum
@@ -918,8 +919,15 @@ def find_gather_sites(
     """``jnp.take(factor, idx, axis=0)`` sites in a wrapper that calls
     one of the kernel programs: each take is one factor-row gather (the
     cache-subsystem request the hierarchy prices) plus one read of the
-    index column driving it.  The enclosing modes-minus-one
-    comprehension multiplies by ``n_inputs``."""
+    index column driving it.  Two forms are recognized:
+
+      * a take inside the modes-minus-one comprehension, one per input
+        factor: the comprehension multiplies by ``n_inputs``;
+      * a single take from a table of all input factors, whose index is
+        ``concatenate([col_k + offset_k for k in other])`` over that
+        comprehension: one column per input factor, so again
+        ``n_inputs`` columns' worth of rows and indices.
+    """
     calls_program = any(
         isinstance(n, ast.Call)
         and (call_name(n) or "").split(".")[-1] in program_names
@@ -934,72 +942,84 @@ def find_gather_sites(
     env = _build_env(fn.node, shape_env, origin_env)
     sites: list[AccessSite] = []
 
-    class _Finder(ast.NodeVisitor):
-        def __init__(self) -> None:
-            self.mult = Poly.const(1)
+    def comp_mult(comp: ast.ListComp) -> Poly:
+        """``n_inputs`` for a modes-minus-one comprehension (written out
+        or iterating a name bound to one), else 1."""
+        gen = comp.generators[0] if comp.generators else None
+        if gen is not None and isinstance(gen.iter, ast.Name):
+            target = defs.get(gen.iter.id, [None])[0]
+            if target is not None and _is_modes_minus_one(target):
+                return Poly.var("n_inputs")
+        elif gen is not None and _is_modes_minus_one(comp):
+            return Poly.var("n_inputs")
+        return Poly.const(1)
 
-        def visit_ListComp(self, node: ast.ListComp) -> None:
-            mult = self.mult
-            comp_mult = Poly.const(1)
-            gen = node.generators[0] if node.generators else None
-            if gen is not None and isinstance(gen.iter, ast.Name):
-                target = defs.get(gen.iter.id, [None])[0]
-                if target is not None and _is_modes_minus_one(target):
-                    comp_mult = Poly.var("n_inputs")
-            elif gen is not None and _is_modes_minus_one(node):
-                comp_mult = Poly.var("n_inputs")
-            self.mult = mult * comp_mult
-            self.generic_visit(node)
-            self.mult = mult
+    def stacked_columns(idx: ast.expr) -> tuple[Poly, Poly] | None:
+        """(multiplier, column length) of an index stacked from one
+        offset column per input factor, else None."""
+        built = reaching_def(fn.node, idx.id, defs) if isinstance(idx, ast.Name) else idx
+        if not (isinstance(built, ast.Call)
+                and (call_name(built) or "").split(".")[-1] == "concatenate"
+                and built.args):
+            return None
+        comp = built.args[0]
+        if not isinstance(comp, ast.ListComp):
+            return None
+        mult = comp_mult(comp)
+        col = comp.elt
+        while isinstance(col, ast.BinOp):  # col_k + offset_k
+            col = col.left
+        shape = _shape_of(col, env, shape_env)
+        if mult == Poly.const(1) or shape is None or len(shape) != 1:
+            return None
+        return mult, shape[0]
 
-        def visit_Call(self, node: ast.Call) -> None:
-            if (call_name(node) or "").split(".")[-1] == "take" and \
-                    len(node.args) >= 2:
-                idx = node.args[1]
-                idx_shape = _shape_of(idx, env, shape_env)
-                if idx_shape is not None and len(idx_shape) == 1:
-                    length = idx_shape[0]
-                    sites.append(
-                        AccessSite(
-                            file=sf.path, line=node.lineno, fn=fn.qualname,
-                            ref=ast.unparse(node.args[0])[:40],
-                            op="load", space="hbm", role="factor_gather",
-                            pred=Pred.EVERY, count=self.mult,
-                            elements=length * Poly.var("rank"),
-                            note="factor-row gather (one row per nonzero)",
-                        )
-                    )
-                    sites.append(
-                        AccessSite(
-                            file=sf.path, line=node.lineno, fn=fn.qualname,
-                            ref=ast.unparse(idx)[:40],
-                            op="load", space="hbm", role="index",
-                            pred=Pred.EVERY, count=self.mult,
-                            elements=length,
-                            note="gather index column",
-                        )
-                    )
-            self.generic_visit(node)
+    def emit(take: ast.Call, mult: Poly, length: Poly) -> None:
+        sites.append(
+            AccessSite(
+                file=sf.path, line=take.lineno, fn=fn.qualname,
+                ref=ast.unparse(take.args[0])[:40],
+                op="load", space="hbm", role="factor_gather",
+                pred=Pred.EVERY, count=mult,
+                elements=length * Poly.var("rank"),
+                note="factor-row gather (one row per nonzero)",
+            )
+        )
+        sites.append(
+            AccessSite(
+                file=sf.path, line=take.lineno, fn=fn.qualname,
+                ref=ast.unparse(take.args[1])[:40],
+                op="load", space="hbm", role="index",
+                pred=Pred.EVERY, count=mult,
+                elements=length,
+                note="gather index column",
+            )
+        )
 
-    # Wrap the comprehension-aware multiplier around the whole body.
-    finder = _Finder()
-    # `other = [k ...]` handled via defs lookup when comprehensions
-    # iterate a named list; direct comprehensions classify themselves.
+    def takes(tree: ast.AST) -> list[ast.Call]:
+        return [
+            n for n in ast.walk(tree)
+            if isinstance(n, ast.Call) and len(n.args) >= 2
+            and (call_name(n) or "").split(".")[-1] == "take"
+        ]
+
+    seen: set[int] = set()
     for node in ast.walk(fn.node):
-        if isinstance(node, ast.ListComp):
-            gen = node.generators[0] if node.generators else None
-            mult = Poly.const(1)
-            if gen is not None and isinstance(gen.iter, ast.Name):
-                target = defs.get(gen.iter.id, [None])[0]
-                if target is not None and _is_modes_minus_one(target):
-                    mult = Poly.var("n_inputs")
-            elif _is_modes_minus_one(node):
-                mult = Poly.var("n_inputs")
-            finder.mult = mult
-            for sub in ast.walk(node):
-                if isinstance(sub, ast.Call):
-                    finder.visit_Call(sub)
-            finder.mult = Poly.const(1)
+        if not isinstance(node, ast.ListComp):
+            continue
+        mult = comp_mult(node)
+        for take in takes(node):
+            if id(take) in seen:
+                continue
+            seen.add(id(take))
+            idx_shape = _shape_of(take.args[1], env, shape_env)
+            if idx_shape is not None and len(idx_shape) == 1:
+                emit(take, mult, idx_shape[0])
+    for take in takes(fn.node):
+        if id(take) not in seen:
+            stacked = stacked_columns(take.args[1])
+            if stacked is not None:
+                emit(take, *stacked)
     return sites
 
 
@@ -1310,9 +1330,13 @@ def find_traffic_censuses(
             (sf, info, info.node.name) for info in index.infos.values()
         )
 
-    # attach gather-wrapper sites to the programs they call
+    # attach gather-wrapper sites to the programs they call; a test's own
+    # wrapper (under tests/) restages rows to check the program against,
+    # and is no part of the program's traffic
     program_by_name = {c.program: c for c in censuses}
     for sf in files:
+        if sf.path.split("/")[0] == "tests":
+            continue
         index = indexes[sf.path]
         for info in index.infos.values():
             if info.node.name in program_by_name:

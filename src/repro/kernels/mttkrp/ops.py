@@ -10,6 +10,7 @@ Responsibilities split exactly as the paper splits them:
 
 from __future__ import annotations
 
+import itertools
 from typing import NamedTuple, Sequence
 
 import jax
@@ -237,9 +238,17 @@ def _mttkrp_pallas_exec(
 ) -> jax.Array:
     """The Pallas leg of the dispatch: gather, kernel call, unpad.
 
-    The gather and lane pad run under the ``mttkrp_gather`` scope and the
-    kernel under ``mttkrp_kernel``: each device op's ``op_name`` names
-    its part in a profiler trace.
+    The gather runs under the ``mttkrp_gather`` scope and the kernel
+    under ``mttkrp_kernel``: each device op's ``op_name`` names its part
+    in a profiler trace.
+
+    The kernel's ``(K, nnz_pad, R_pad)`` factor operand is written by one
+    gather: the K input factors are lane-padded and concatenated into one
+    table, each index column is offset to its factor's first row there,
+    and a single ``take`` fetches every row.  Its output is the operand
+    itself, so no stack or pad copies it again; ``mode="clip"`` adds no
+    fill mask, and clips nothing, since the plan keeps every index in
+    range (padding rows point at each factor's row 0).
     """
     mode = plan.mode
     rank = factors[0].shape[1]
@@ -247,11 +256,18 @@ def _mttkrp_pallas_exec(
 
     other = [k for k in range(len(factors)) if k != mode]
     with jax.named_scope("mttkrp_gather"):
-        gathered = jnp.stack(
-            [jnp.take(factors[k], bufs.indices[:, k], axis=0) for k in other]
-        )  # (K, nnz_pad, R)
+        # Pad each factor, not the table: XLA moves a pad of the table
+        # past the gather, which brings back a copy of the gathered rows.
+        inputs = [factors[k] for k in other]
         if r_pad != rank:
-            gathered = jnp.pad(gathered, ((0, 0), (0, 0), (0, r_pad - rank)))
+            inputs = [jnp.pad(f, ((0, 0), (0, r_pad - rank))) for f in inputs]
+        table = jnp.concatenate(inputs)  # (sum I_k, R_pad)
+        starts = itertools.accumulate(factors[k].shape[0] for k in other)
+        first_row = dict(zip(other, [0, *starts]))
+        rows = jnp.concatenate([bufs.indices[:, k] + first_row[k] for k in other])
+        gathered = jnp.take(table, rows, axis=0, mode="clip").reshape(
+            len(other), -1, r_pad
+        )  # (K, nnz_pad, R_pad)
 
     with jax.named_scope("mttkrp_kernel"):
         out = mttkrp_pallas_call(

@@ -290,6 +290,75 @@ def test_backends_bitwise_consistent_with_ref_tolerance():
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
+def _stacked_operand_mttkrp(plan, factors, bufs):
+    """The kernel fed its factor operand built the per-factor way: one
+    ``take`` per input factor, stacked, then lane-padded."""
+    from repro.kernels.mttkrp.kernel import LANE, mttkrp_pallas_call
+
+    rank = factors[0].shape[1]
+    r_pad = -(-rank // LANE) * LANE
+    other = [k for k in range(len(factors)) if k != plan.mode]
+    gathered = jnp.stack([jnp.take(factors[k], bufs.indices[:, k], axis=0) for k in other])
+    gathered = jnp.pad(gathered, ((0, 0), (0, 0), (0, r_pad - rank)))
+    out = mttkrp_pallas_call(
+        bufs.tile_block, bufs.values, bufs.local_row, gathered,
+        tile_nnz=plan.tile_nnz, rows_per_block=plan.rows_per_block,
+        num_blocks=plan.num_blocks, interpret=True,
+    )
+    return out[: plan.shape[plan.mode], :rank].astype(factors[plan.mode].dtype)
+
+
+def _rows_100_to_199_empty():
+    from repro.core.sparse_tensor import SparseTensor
+
+    idx = np.array([[0, 0, 0], [1, 1, 1], [250, 2, 2]], np.int32)
+    return SparseTensor(idx, np.array([1.0, 2.0, 3.0], np.float32), (300, 4, 4))
+
+
+@pytest.mark.parametrize(
+    "case,rank",
+    [("n3", 16), ("n5", 16), ("n3", 128), ("n5", 128), ("empty_blocks", 16),
+     ("fused_restarts_2", 16)],
+)
+def test_single_gather_operand_is_bit_identical(case, rank):
+    """The one-table gather hands the kernel the same operand as one
+    gather per factor followed by stack and lane pad: the MTTKRP is
+    bit for bit the same, padding rows and empty blocks included, and
+    under the restart vmap of ``FusedCPALS``."""
+    from repro.core.cp_als_fused import FusedCPALS
+    from repro.kernels.mttkrp.ops import get_plan, mttkrp_from_plan, plan_device_buffers
+
+    t = {
+        "n3": lambda: random_sparse_tensor((70, 33, 41), nnz=500, seed=41),
+        "n5": lambda: random_sparse_tensor((9, 8, 7, 6, 5), nnz=300, seed=42),
+        "empty_blocks": _rows_100_to_199_empty,
+        "fused_restarts_2": lambda: random_sparse_tensor((40, 30, 20), nnz=300, seed=43),
+    }[case]()
+    if case == "fused_restarts_2":
+        executor = FusedCPALS(t, rank, impl="pallas", backend="interpret",
+                              tile_nnz=64, rows_per_block=32)
+        mode_bufs = executor.operands[0]
+        facs = [jnp.stack([f, -f]) for f in _factors(t.shape, rank, seed=43)]
+        for mode in range(t.nmodes):
+            plan = get_plan(t, mode, tile_nnz=64, rows_per_block=32)
+            bufs = mode_bufs[mode]
+            got = jax.jit(jax.vmap(
+                lambda fs: mttkrp_from_plan(plan, fs, backend="interpret", bufs=bufs)
+            ))(facs)
+            want = jax.jit(jax.vmap(lambda fs: _stacked_operand_mttkrp(plan, fs, bufs)))(facs)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        return
+    facs = _factors(t.shape, rank, seed=rank)
+    outs = []
+    for mode in range(t.nmodes):
+        plan = build_mttkrp_plan(t, mode, tile_nnz=64, rows_per_block=32)
+        outs.append(np.asarray(mttkrp_from_plan(plan, facs, backend="interpret")))
+        want = _stacked_operand_mttkrp(plan, facs, plan_device_buffers(plan))
+        np.testing.assert_array_equal(outs[-1], np.asarray(want))
+    if case == "empty_blocks":
+        assert np.all(outs[0][100:200] == 0.0)
+
+
 def test_pallas_call_geometry_valueerrors():
     """Geometry violations raise ValueError with the offending shapes
     (replacing bare asserts that vanish under ``python -O``)."""

@@ -24,7 +24,7 @@ from repro.core.sparse_tensor import random_sparse_tensor
 from repro.data.frostt import FROSTT_TENSORS, PAPER_RANK
 from repro.dse.autotune import TuneSpace
 from repro.kernels.mttkrp.kernel import LANE, mttkrp_pallas_call
-from repro.kernels.mttkrp.ops import PlanBuffers, get_plan
+from repro.kernels.mttkrp.ops import PlanBuffers, get_plan, mttkrp_from_plan
 
 # The chip smoke test's single job (chip_smoke.py): NELL-2 cut to 3.9 M nnz.
 NELL2 = FROSTT_TENSORS["NELL-2"]
@@ -134,19 +134,40 @@ SCOPES = set().union(*SCOPE_CHAINS)
 NO_DEVICE_OP = {"parameter", "constant", "bitcast", "tuple", "get-tuple-element"}
 
 
+def _entry_lines(hlo_text: str) -> dict[str, tuple[str, str]]:
+    """Instruction name -> (its line, its opcode) for every instruction
+    of the entry computation of compiled HLO text."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    entry = entry[: entry.index("\n}\n")]
+    lines = {}
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? ([a-z][\w\-]*)\(", line)
+        if m:
+            lines[m.group(1)] = (line, m.group(2))
+    return lines
+
+
 def _entry_ops(hlo_text: str) -> dict[str, tuple[str, str | None]]:
     """Instruction name -> (its line, its ``op_name`` or None) for the
     entry computation of compiled HLO text: the ops that run on the
     device (``NO_DEVICE_OP`` instructions are left out)."""
-    entry = hlo_text[hlo_text.index("\nENTRY "):]
-    entry = entry[: entry.index("\n}\n")]
     ops = {}
-    for line in entry.splitlines()[1:]:
-        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .*? ([a-z][\w\-]*)\(", line)
-        if m and m.group(2) not in NO_DEVICE_OP:
+    for name, (line, opcode) in _entry_lines(hlo_text).items():
+        if opcode not in NO_DEVICE_OP:
             op_name = re.search(r'op_name="([^"]*)"', line)
-            ops[m.group(1)] = (line, op_name.group(1) if op_name else None)
+            ops[name] = (line, op_name.group(1) if op_name else None)
     return ops
+
+
+def _kernel_factor_operand(hlo_text: str, kernel_line: str) -> str:
+    """The instruction that computes a kernel call's last operand (the
+    gathered factor rows), looked up through bitcasts."""
+    lines = _entry_lines(hlo_text)
+    name = re.search(r"custom-call\((.*?)\)", kernel_line).group(1).split(", ")[-1]
+    name = name.lstrip("%")
+    while lines[name][1] == "bitcast":
+        name = re.search(r"bitcast\(%([\w.\-]+)\)", lines[name][0]).group(1)
+    return name
 
 
 def _scopes(op_name: str) -> set[str]:
@@ -158,14 +179,15 @@ def test_fused_sweep_ops_carry_the_program_scopes_on_v5e(smoke_sweep):
     profiler trace reads back), and no op of the program's own code
     falls outside the scopes or between two of them."""
     _, compiled = smoke_sweep
-    ops = _entry_ops(compiled.as_text())
+    hlo = compiled.as_text()
+    ops = _entry_ops(hlo)
     kernels = [(line, name) for line, name in ops.values() if "tpu_custom_call" in line]
     assert len(kernels) == 3  # one MTTKRP per mode
     for line, op_name in kernels:
         assert _scopes(op_name) == {"mttkrp", "mttkrp_kernel"}
-        # the kernel's last operand is the stacked, lane-padded gather
-        gathered = re.search(r"custom-call\((.*?)\)", line).group(1).split(", ")[-1]
-        assert _scopes(ops[gathered.lstrip("%")][1]) == {"mttkrp", "mttkrp_gather"}
+        # the kernel's last operand comes from the gather
+        gathered = _kernel_factor_operand(hlo, line)
+        assert _scopes(ops[gathered][1]) == {"mttkrp", "mttkrp_gather"}
     solve = [name for _, name in ops.values() if name and "jit(solve)" in name]
     assert solve and all(_scopes(name) == {"als_update"} for name in solve)
     assert any(name and _scopes(name) == {"als_fit"} for _, name in ops.values())
@@ -175,3 +197,48 @@ def test_fused_sweep_ops_carry_the_program_scopes_on_v5e(smoke_sweep):
     assert program
     for name in program:
         assert _scopes(name) in SCOPE_CHAINS, name
+
+
+def test_mttkrp_factor_operand_is_one_gather_on_v5e(one_chip):
+    """At the smoke size, the kernel's ``(K, nnz_pad, R_pad)`` factor
+    operand is written by one gather fusion, seen through a bitcast: no
+    select masks the gathered rows, no stack or pad copies them, and the
+    compiled temporaries hold that operand about once (a gather per
+    factor, masked, then stacked and padded, takes twice)."""
+    tensor = random_sparse_tensor(NELL2.dims, nnz=20_000, seed=0, zipf_a=NELL2.zipf_alpha)
+    plan = get_plan(tensor, 0)
+    nnz_pad = -(-(SMOKE_NNZ + plan.num_blocks * plan.tile_nnz) // plan.tile_nnz)
+    nnz_pad *= plan.tile_nnz
+    bufs = PlanBuffers(
+        indices=_spec((nnz_pad, 3), jnp.int32, one_chip),
+        values=_spec((nnz_pad,), jnp.float32, one_chip),
+        local_row=_spec((nnz_pad,), jnp.int32, one_chip),
+        tile_block=_spec((nnz_pad // plan.tile_nnz,), jnp.int32, one_chip),
+    )
+    factors = tuple(_spec((d, RANK), jnp.float32, one_chip) for d in NELL2.dims)
+    compiled = jax.jit(
+        lambda b, fs: mttkrp_from_plan(plan, fs, backend="mosaic", bufs=b)
+    ).lower(bufs, factors).compile()
+
+    r_pad = -(-RANK // LANE) * LANE
+    operand_bytes = (len(NELL2.dims) - 1) * nnz_pad * r_pad * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.1 * operand_bytes
+
+    hlo = compiled.as_text()
+    lines = _entry_lines(hlo)
+    (kernel,) = [line for line, _ in lines.values() if "tpu_custom_call" in line]
+    line, opcode = lines[_kernel_factor_operand(hlo, kernel)]
+    assert opcode == "fusion"
+    body = re.search(r"calls=%([\w.\-]+)", line).group(1)
+    body = hlo[hlo.index(f"\n%{body} "):]
+    assert " gather(" in body[: body.index("\n}\n")]
+
+    def shape(name):  # an instruction's result shape, as printed
+        line, opcode = lines[name]
+        return line.split(" = ", 1)[1].split(f" {opcode}(", 1)[0]
+
+    for name, (line, opcode) in lines.items():
+        if "select" in name:
+            operands = re.search(rf" {opcode}\((.*?)\)", line).group(1)
+            for read in [name] + re.findall(r"%([\w.\-]+)", operands):
+                assert str(nnz_pad) not in shape(read), line
