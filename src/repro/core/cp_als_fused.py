@@ -246,8 +246,10 @@ class FusedCPALS:
             def body(carry, _):
                 factors, weights = carry
                 for mode in range(self.nmodes):  # unrolled at trace time
-                    m = self._mttkrp(factors, mode, mode_operands[mode])
-                    factors, weights = _mode_update(factors, weights, m, mode)
+                    # names each mode's device ops in a trace
+                    with jax.named_scope(f"als_mode{mode}"):
+                        m = self._mttkrp(factors, mode, mode_operands[mode])
+                        factors, weights = _mode_update(factors, weights, m, mode)
                 fit = _fit(*fit_operands, factors, weights)
                 return (factors, weights), fit
 

@@ -248,6 +248,32 @@ def build_mttkrp_plan(
     )
 
 
+def _first_of_each_row(idx: np.ndarray, shape: Sequence[int]) -> np.ndarray:
+    """Positions of the first occurrence of each distinct coordinate row
+    of ``idx`` (n, N), in lexicographic row order.
+
+    Consecutive modes are packed into one int64 key while their index
+    space fits it (LBNL-network's five modes span ~3.9e19 coordinates,
+    more than 2**63); a stable sort over the keys then keeps each row's
+    first occurrence.  Where every mode fits one key this is
+    ``np.unique(flat_key, return_index=True)``, draw for draw.
+    """
+    n = idx.shape[0]
+    keys, key, span = [], np.zeros(n, np.int64), 1
+    for col, dim in zip(idx.T, shape):
+        if span * int(dim) > np.iinfo(np.int64).max:
+            keys.append(key)
+            key, span = np.zeros(n, np.int64), 1
+        key = key * int(dim) + col
+        span *= int(dim)
+    keys.append(key)
+    order = np.lexsort(keys[::-1])  # np.lexsort sorts by its last key first
+    new = np.ones(n, dtype=bool)
+    sorted_keys = [k[order] for k in keys]
+    new[1:] = np.any([k[1:] != k[:-1] for k in sorted_keys], axis=0)
+    return order[new]
+
+
 def random_sparse_tensor(
     shape: Sequence[int],
     nnz: int,
@@ -318,10 +344,7 @@ def random_sparse_tensor(
             perm = rng.permutation(dim)  # decorrelate rank from index value
             cols.append(perm[np.clip(ranks, 0, dim - 1)])
     idx = np.stack(cols, axis=1)
-    # Coalesce duplicates.
-    keys = np.ravel_multi_index(tuple(idx.T), shape, mode="wrap")
-    _, first = np.unique(keys, return_index=True)
-    idx = idx[first].astype(np.int32)
+    idx = idx[_first_of_each_row(idx, shape)].astype(np.int32)  # coalesce duplicates
     vals = rng.standard_normal(idx.shape[0]).astype(dtype)
     if shuffle:
         perm = rng.permutation(idx.shape[0])

@@ -238,9 +238,10 @@ def _mttkrp_pallas_exec(
 ) -> jax.Array:
     """The Pallas leg of the dispatch: gather, kernel call, unpad.
 
-    The gather runs under the ``mttkrp_gather`` scope and the kernel
-    under ``mttkrp_kernel``: each device op's ``op_name`` names its part
-    in a profiler trace.
+    The gather runs under the ``mttkrp_gather`` scope, its table under
+    ``mttkrp_gather/mttkrp_table``, and the kernel under
+    ``mttkrp_kernel``: each device op's ``op_name`` names its part in a
+    profiler trace.
 
     The kernel's ``(K, nnz_pad, R_pad)`` factor operand is written by one
     gather: the K input factors are lane-padded and concatenated into one
@@ -256,12 +257,13 @@ def _mttkrp_pallas_exec(
 
     other = [k for k in range(len(factors)) if k != mode]
     with jax.named_scope("mttkrp_gather"):
-        # Pad each factor, not the table: XLA moves a pad of the table
-        # past the gather, which brings back a copy of the gathered rows.
-        inputs = [factors[k] for k in other]
-        if r_pad != rank:
-            inputs = [jnp.pad(f, ((0, 0), (0, r_pad - rank))) for f in inputs]
-        table = jnp.concatenate(inputs)  # (sum I_k, R_pad)
+        with jax.named_scope("mttkrp_table"):
+            # Pad each factor, not the table: XLA moves a pad of the table
+            # past the gather, which brings back a copy of the gathered rows.
+            inputs = [factors[k] for k in other]
+            if r_pad != rank:
+                inputs = [jnp.pad(f, ((0, 0), (0, r_pad - rank))) for f in inputs]
+            table = jnp.concatenate(inputs)  # (sum I_k, R_pad)
         starts = itertools.accumulate(factors[k].shape[0] for k in other)
         first_row = dict(zip(other, [0, *starts]))
         rows = jnp.concatenate([bufs.indices[:, k] + first_row[k] for k in other])

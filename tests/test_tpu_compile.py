@@ -242,3 +242,74 @@ def test_mttkrp_factor_operand_is_one_gather_on_v5e(one_chip):
             operands = re.search(rf" {opcode}\((.*?)\)", line).group(1)
             for read in [name] + re.findall(r"%([\w.\-]+)", operands):
                 assert str(nnz_pad) not in shape(read), line
+
+
+# LBNL-network at its published size (the ``lbnl.sweep`` cell): 1.7 M
+# nonzeros after coalescing, and each mode's nnz_pad from the cell's
+# seed-0 plans at tile (256, 256); mode 4 holds 3,392 blocks in 8,155 tiles.
+LBNL = FROSTT_TENSORS["LBNL"]
+LBNL_NNZ = 1_700_000
+LBNL_NNZ_PAD = (1_701_120, 1_702_400, 1_700_608, 1_701_888, 2_087_680)
+MODE_SCOPE = re.compile(r"als_mode(\d+)")
+
+
+@pytest.fixture(scope="module")
+def lbnl_sweep(one_chip):
+    """The LBNL fused sweep, compiled with its full-size operands as
+    arguments: ``(compiled, entry ops)``."""
+    tensor = random_sparse_tensor(LBNL.dims, nnz=20_000, seed=0, zipf_a=LBNL.zipf_alpha)
+    executor = FusedCPALS(tensor, RANK, impl="pallas", backend="mosaic")
+    nmodes = len(LBNL.dims)
+    mode_specs = tuple(
+        PlanBuffers(
+            indices=_spec((n, nmodes), jnp.int32, one_chip),
+            values=_spec((n,), jnp.float32, one_chip),
+            local_row=_spec((n,), jnp.int32, one_chip),
+            tile_block=_spec((n // 256,), jnp.int32, one_chip),
+        )
+        for n in LBNL_NNZ_PAD
+    )
+    fit_specs = (
+        _spec((), jnp.float32, one_chip),
+        _spec((LBNL_NNZ, nmodes), jnp.int32, one_chip),
+        _spec((LBNL_NNZ,), jnp.float32, one_chip),
+    )
+    factors = tuple(_spec((d, RANK), jnp.float32, one_chip) for d in LBNL.dims)
+    weights = _spec((RANK,), jnp.float32, one_chip)
+    compiled = executor.sweep_fn(1, False).lower(
+        (mode_specs, fit_specs), factors, weights
+    ).compile()
+    return compiled, _entry_ops(compiled.as_text())
+
+
+def _modes(op_name: str) -> list[int]:
+    return [int(m.group(1)) for part in re.split(r"[/;]", op_name)
+            if (m := MODE_SCOPE.fullmatch(part))]
+
+
+def test_lbnl_sweep_fits_one_v5e_at_its_published_size(lbnl_sweep):
+    compiled, _ = lbnl_sweep
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_lbnl_sweep_names_each_mode_and_the_gather_table_on_v5e(lbnl_sweep):
+    """Five Mosaic kernels, one per mode, each under the MTTKRP kernel's
+    scopes and exactly one ``als_mode<m>``; the gather table's ops under
+    ``mttkrp_table`` inside each mode's gather."""
+    _, ops = lbnl_sweep
+    kernels = [name for line, name in ops.values() if "tpu_custom_call" in line]
+    assert len(kernels) == 5
+    for op_name in kernels:
+        assert _scopes(op_name) == {"mttkrp", "mttkrp_kernel"}
+        assert len(_modes(op_name)) == 1
+    assert sorted(_modes(name)[0] for name in kernels) == list(range(5))
+    table = [name for _, name in ops.values() if name and "/mttkrp_table/" in name]
+    for op_name in table:
+        assert _scopes(op_name) == {"mttkrp", "mttkrp_gather"}
+        assert len(_modes(op_name)) == 1
+    assert {_modes(name)[0] for name in table} == set(range(5))
+    # the factors' lane pads all sit in the table
+    pads = [name for line, name in ops.values()
+            if name and " pad(" in line and "/mttkrp/" in name]
+    assert pads and all("/mttkrp_table/" in name for name in pads)

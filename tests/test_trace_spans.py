@@ -4,7 +4,8 @@ profiler trace on the CPU (``FusedCPALS.run``, DESIGN.md §11).
 ``cp_als.run`` holds ``cp_als.init`` and, per block, ``cp_als.block``
 (stats ``sweeps`` and ``new_program``) and ``cp_als.fit_sync``; the
 sweep's device ops carry the ``mttkrp``, ``mttkrp_gather``,
-``mttkrp_kernel``, ``als_update`` and ``als_fit`` scopes.
+``mttkrp_kernel``, ``als_update`` and ``als_fit`` scopes, each mode's
+under its ``als_mode<m>``, and the gather's table under ``mttkrp_table``.
 """
 
 from __future__ import annotations
@@ -12,8 +13,10 @@ from __future__ import annotations
 import re
 
 import jax
+import jax.numpy as jnp
 import pytest
 
+from repro.core.cp_als import cp_init
 from repro.core.cp_als_fused import FusedCPALS
 from repro.core.sparse_tensor import random_sparse_tensor
 
@@ -69,3 +72,19 @@ def test_lowered_sweep_holds_every_scope(tensor, backend):
     ).as_text(debug_info=True)
     parts = set(re.split(r'[/;"]', text))
     assert set(SCOPES) <= parts
+
+
+def test_lowered_sweep_names_each_mode_and_the_gather_table():
+    """``als_mode<m>`` around each mode's MTTKRP and update, and
+    ``mttkrp_table`` inside ``mttkrp_gather`` (the Pallas leg's factor
+    table), on a 5-mode tensor."""
+    tensor = random_sparse_tensor((6, 9, 6, 9, 400), nnz=300, seed=4)
+    executor = FusedCPALS(tensor, 3, impl="pallas", backend="interpret")
+    factors = tuple(cp_init(tensor, 3))
+    text = executor.sweep_fn(1, False).lower(
+        executor.operands, factors, jnp.ones((3,))
+    ).as_text(debug_info=True)
+    parts = set(re.split(r'[/;"]', text))
+    assert {f"als_mode{m}" for m in range(5)} | {"mttkrp_table"} <= parts
+    assert "als_mode5" not in parts
+    assert re.search(r"als_mode\d/mttkrp/mttkrp_gather/mttkrp_table/", text)
