@@ -926,7 +926,10 @@ def find_gather_sites(
       * a single take from a table of all input factors, whose index is
         ``concatenate([col_k + offset_k for k in other])`` over that
         comprehension: one column per input factor, so again
-        ``n_inputs`` columns' worth of rows and indices.
+        ``n_inputs`` columns' worth of rows and indices.  The element may
+        be ``col_k + offset_k if k in table else zeros``: a factor left
+        out of the table is read by a take of its own, outside any
+        comprehension, in place of its column here, so the count holds.
     """
     calls_program = any(
         isinstance(n, ast.Call)
@@ -967,6 +970,8 @@ def find_gather_sites(
             return None
         mult = comp_mult(comp)
         col = comp.elt
+        if isinstance(col, ast.IfExp):  # col_k + offset_k if k in table else zeros
+            col = col.body
         while isinstance(col, ast.BinOp):  # col_k + offset_k
             col = col.left
         shape = _shape_of(col, env, shape_env)

@@ -229,6 +229,32 @@ def mttkrp_from_plan(
     return _mttkrp_pallas_exec(plan, factors, bufs, interpret=backend == "interpret")
 
 
+# Bytes of lane-padded factor rows one gather table may hold.  XLA:TPU
+# stages a gather's table in on-chip VMEM (memory space S(1)) when it is
+# small enough, and then gathers at about HBM write speed; a larger table
+# stays in HBM and each gathered row is a random HBM read.  Described-v5e
+# compiles (jax 0.9.0) of a standalone take of (rows, 128) float32 place
+# tables of up to 200,000 rows (102 MB) in S(1), and leave 262,144 rows
+# (134 MB) in HBM.  32 MiB sits above NELL-2's largest table (40,900 rows,
+# 21 MB) and well under that limit.
+TABLE_BYTES = 32 << 20
+
+
+def _table_factors(factors: Sequence[jax.Array], other: list[int], r_pad: int) -> list[int]:
+    """The input factors that go into the one gather table, in ``other``'s
+    order: the shortest first, while the table's lane-padded bytes stay
+    within ``TABLE_BYTES``, and always the shortest.  Each factor left out
+    is gathered apart, from its own lane-padded copy."""
+    row_bytes = r_pad * factors[other[0]].dtype.itemsize
+    kept, used = set(), 0
+    for k in sorted(other, key=lambda k: factors[k].shape[0]):
+        used += factors[k].shape[0] * row_bytes
+        if kept and used > TABLE_BYTES:
+            break
+        kept.add(k)
+    return [k for k in other if k in kept]
+
+
 def _mttkrp_pallas_exec(
     plan: MTTKRPPlan,
     factors: Sequence[jax.Array],
@@ -250,26 +276,44 @@ def _mttkrp_pallas_exec(
     itself, so no stack or pad copies it again; ``mode="clip"`` adds no
     fill mask, and clips nothing, since the plan keeps every index in
     range (padding rows point at each factor's row 0).
+
+    A factor too tall for the table (``_table_factors``) is gathered
+    apart, under ``mttkrp_gather/mttkrp_gather_apart``: the table gather
+    fills its slot with the table's row 0, and its own rows, taken from
+    its lane-padded copy, are written over that slot in place.  The
+    operand holds the same rows in the same order either way.
     """
     mode = plan.mode
     rank = factors[0].shape[1]
     r_pad = -(-rank // LANE) * LANE
 
     other = [k for k in range(len(factors)) if k != mode]
+    in_table = _table_factors(factors, other, r_pad)
     with jax.named_scope("mttkrp_gather"):
         with jax.named_scope("mttkrp_table"):
             # Pad each factor, not the table: XLA moves a pad of the table
             # past the gather, which brings back a copy of the gathered rows.
-            inputs = [factors[k] for k in other]
+            padded = {k: factors[k] for k in other}
             if r_pad != rank:
-                inputs = [jnp.pad(f, ((0, 0), (0, r_pad - rank))) for f in inputs]
-            table = jnp.concatenate(inputs)  # (sum I_k, R_pad)
-        starts = itertools.accumulate(factors[k].shape[0] for k in other)
-        first_row = dict(zip(other, [0, *starts]))
-        rows = jnp.concatenate([bufs.indices[:, k] + first_row[k] for k in other])
+                padded = {k: jnp.pad(f, ((0, 0), (0, r_pad - rank))) for k, f in padded.items()}
+            table = jnp.concatenate([padded[k] for k in in_table])  # (sum I_k, R_pad)
+        starts = itertools.accumulate(factors[k].shape[0] for k in in_table)
+        first_row = dict(zip(in_table, [0, *starts]))
+        # An apart factor's slot gathers the table's row 0 until its own
+        # rows overwrite it below.
+        rows = jnp.concatenate([
+            bufs.indices[:, k] + first_row[k] if k in first_row
+            else jnp.zeros_like(bufs.indices[:, k])
+            for k in other
+        ])
         gathered = jnp.take(table, rows, axis=0, mode="clip").reshape(
             len(other), -1, r_pad
         )  # (K, nnz_pad, R_pad)
+        for slot, k in enumerate(other):
+            if k not in first_row:
+                with jax.named_scope("mttkrp_gather_apart"):
+                    own = jnp.take(padded[k], bufs.indices[:, k], axis=0, mode="clip")
+                    gathered = jax.lax.dynamic_update_slice(gathered, own[None], (slot, 0, 0))
 
     with jax.named_scope("mttkrp_kernel"):
         out = mttkrp_pallas_call(

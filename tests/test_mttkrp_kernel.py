@@ -359,6 +359,49 @@ def test_single_gather_operand_is_bit_identical(case, rank):
         assert np.all(outs[0][100:200] == 0.0)
 
 
+@pytest.mark.parametrize(
+    "shape,mode,table_rows,apart,rank",
+    [
+        ((70, 12, 40), 2, 20, [0], 16),  # the first slot
+        ((40, 12, 70), 0, 0, [2], 16),  # the last slot; a table of the shortest alone
+        ((40, 12, 70), 0, 20, [2], 128),  # no lane pad
+        ((9, 50, 7, 8), 0, 20, [1], 16),  # the first of three slots
+        ((9, 50, 7, 8), 3, 20, [1], 16),  # the middle slot
+        ((9, 40, 7, 30, 5), 4, 20, [1, 3], 16),  # two apart, both middle
+        ((9, 40, 7, 30, 5), 0, 20, [1, 3], 16),  # two apart, first and third
+    ],
+)
+def test_apart_gather_operand_is_bit_identical(monkeypatch, shape, mode, table_rows, apart, rank):
+    """With the table's budget cut so that one or two factors are
+    gathered apart, the kernel gets the same factor operand as from the
+    one table, bit for bit, and the MTTKRP matches the reference."""
+    from repro.kernels.mttkrp import ops
+    from repro.kernels.mttkrp.kernel import LANE
+
+    t = random_sparse_tensor(shape, nnz=300, seed=len(shape) + mode)
+    facs = _factors(t.shape, rank, seed=mode)
+    plan = build_mttkrp_plan(t, mode, tile_nnz=64, rows_per_block=32)
+    operands = []
+
+    def kernel(*args, **kwargs):
+        operands.append(np.asarray(args[3]))
+        return ops_kernel(*args, **kwargs)
+
+    ops_kernel = ops.mttkrp_pallas_call
+    monkeypatch.setattr(ops, "mttkrp_pallas_call", kernel)
+    one_table = np.asarray(ops.mttkrp_from_plan(plan, facs, backend="interpret"))
+    r_pad = -(-rank // LANE) * LANE
+    monkeypatch.setattr(ops, "TABLE_BYTES", table_rows * r_pad * 4)
+    other = [k for k in range(len(shape)) if k != mode]
+    assert [k for k in other if k not in ops._table_factors(facs, other, r_pad)] == apart
+    split = np.asarray(ops.mttkrp_from_plan(plan, facs, backend="interpret"))
+
+    np.testing.assert_array_equal(operands[1], operands[0])
+    np.testing.assert_array_equal(split, one_table)
+    want = mttkrp_ref(t, facs, mode)
+    np.testing.assert_allclose(split, np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
 def test_pallas_call_geometry_valueerrors():
     """Geometry violations raise ValueError with the offending shapes
     (replacing bare asserts that vanish under ``python -O``)."""

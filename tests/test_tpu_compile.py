@@ -12,6 +12,7 @@ these tests in this one file, so one worker owns the library.
 
 from __future__ import annotations
 
+import math
 import re
 
 import jax
@@ -313,3 +314,128 @@ def test_lbnl_sweep_names_each_mode_and_the_gather_table_on_v5e(lbnl_sweep):
     pads = [name for line, name in ops.values()
             if name and " pad(" in line and "/mttkrp/" in name]
     assert pads and all("/mttkrp_table/" in name for name in pads)
+
+
+# A gather's table that XLA:TPU places in on-chip VMEM prints memory space S(1).
+VMEM = "S(1)"
+
+
+def _operand_names(line: str, opcode: str) -> list[str]:
+    return re.findall(r"%([\w.\-]+)", re.search(rf" {opcode}\((.*?)\)", line).group(1))
+
+
+def _through(lines: dict[str, tuple[str, str]], name: str, opcodes=("bitcast",)) -> str:
+    """The instruction that ``name`` passes on, looked up through
+    ``opcodes`` of one operand."""
+    while lines[name][1] in opcodes:
+        name = _operand_names(*lines[name])[0]
+    return name
+
+
+def _fusion_body(hlo_text: str, line: str) -> str:
+    """The computation a fusion calls, else ``""``."""
+    called = re.search(r"calls=%([\w.\-]+)", line)
+    if not called:
+        return ""
+    body = hlo_text[hlo_text.index(f"\n%{called.group(1)} "):]
+    return body[: body.index("\n}\n")]
+
+
+def _shape(lines: dict[str, tuple[str, str]], name: str) -> str:
+    line, opcode = lines[name]
+    return line.split(" = ", 1)[1].split(f" {opcode}(", 1)[0]
+
+
+def _elements(shape: str) -> list[int]:
+    """The element count of each array in a shape (a tuple has several)."""
+    return [math.prod(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"\w+\[([\d,]*)\]", shape)]
+
+
+def _factor_operand_writes(hlo_text: str, kernel_line: str) -> tuple[str, list[str]]:
+    """(the table gather, the slot updates in the order they run) that
+    write a kernel call's factor operand, seen through bitcasts."""
+    lines = _entry_lines(hlo_text)
+    name = _kernel_factor_operand(hlo_text, kernel_line)
+    updates = []
+    while " dynamic-update-slice(" in _fusion_body(hlo_text, lines[name][0]):
+        updates.insert(0, name)
+        name = _through(lines, _operand_names(*lines[name])[0])
+    assert " gather(" in _fusion_body(hlo_text, lines[name][0])
+    return name, updates
+
+
+def _gather_table(lines: dict[str, tuple[str, str]], gather: str) -> str:
+    """The shape of the table a gather fusion reads (its first operand)."""
+    return _shape(lines, _operand_names(*lines[gather])[0])
+
+
+def test_lbnl_time_factor_is_gathered_apart_on_v5e(one_chip):
+    """At LBNL's sizes, mode 0's factor operand: one gather from a table
+    of the three small factors, placed in on-chip memory, fills every
+    slot, and the 868,100-row time factor's rows come from a gather of
+    their own, written over its slot in place.  Temporaries hold the
+    operand and one apart gather's rows about once, and no op copies the
+    whole operand."""
+    mode, nnz_pad = 0, LBNL_NNZ_PAD[0]
+    tensor = random_sparse_tensor(LBNL.dims, nnz=20_000, seed=0, zipf_a=LBNL.zipf_alpha)
+    plan = get_plan(tensor, mode)
+    nmodes = len(LBNL.dims)
+    bufs = PlanBuffers(
+        indices=_spec((nnz_pad, nmodes), jnp.int32, one_chip),
+        values=_spec((nnz_pad,), jnp.float32, one_chip),
+        local_row=_spec((nnz_pad,), jnp.int32, one_chip),
+        tile_block=_spec((nnz_pad // plan.tile_nnz,), jnp.int32, one_chip),
+    )
+    factors = tuple(_spec((d, RANK), jnp.float32, one_chip) for d in LBNL.dims)
+    compiled = jax.jit(
+        lambda b, fs: mttkrp_from_plan(plan, fs, backend="mosaic", bufs=b)
+    ).lower(bufs, factors).compile()
+
+    r_pad = -(-RANK // LANE) * LANE
+    slot_bytes = nnz_pad * r_pad * 4
+    operand_bytes = (nmodes - 1) * slot_bytes
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.1 * (operand_bytes + slot_bytes)
+
+    hlo = compiled.as_text()
+    lines = _entry_lines(hlo)
+    (kernel,) = [line for line, _ in lines.values() if "tpu_custom_call" in line]
+    gather, (update,) = _factor_operand_writes(hlo, kernel)
+    table = _gather_table(lines, gather)
+    assert table.startswith(f"f32[{sum(LBNL.dims[1:4])},{r_pad}]") and VMEM in table
+    apart = _through(lines, _operand_names(*lines[update])[1], ("bitcast", "copy"))
+    assert " gather(" in _fusion_body(hlo, lines[apart][0])
+    assert _gather_table(lines, apart).startswith(f"f32[{LBNL.dims[4]},")
+    for name, (line, opcode) in lines.items():
+        if operand_bytes // 4 in _elements(_shape(lines, name)):
+            assert name in (gather, update) or opcode == "bitcast", line
+
+
+@pytest.mark.parametrize("sweep", ["smoke_sweep", "lbnl_sweep"])
+def test_only_a_tall_factor_is_gathered_apart_in_the_sweep_on_v5e(request, sweep):
+    """In the compiled sweeps every MTTKRP's gather table sits in on-chip
+    memory: LBNL's modes 0-3 gather the time factor apart, one slot
+    update each, while its mode 4 and every NELL-2 mode keep the one
+    gather.  No op copies a whole factor operand, and the LBNL sweep's
+    temporaries stay within a third of a chip."""
+    compiled = {"smoke_sweep": lambda f: f[1], "lbnl_sweep": lambda f: f[0]}[sweep](
+        request.getfixturevalue(sweep)
+    )
+    hlo = compiled.as_text()
+    lines = _entry_lines(hlo)
+    updates, writes, sizes = {}, set(), set()
+    for line, opcode in lines.values():
+        if "tpu_custom_call" in line:
+            (mode,) = _modes(re.search(r'op_name="([^"]*)"', line).group(1))
+            gather, updates[mode] = _factor_operand_writes(hlo, line)
+            assert VMEM in _gather_table(lines, gather)
+            writes |= {gather, *updates[mode]}
+            sizes |= set(_elements(_shape(lines, gather)))
+    for name, (line, opcode) in lines.items():  # no op copies a whole operand
+        if sizes & set(_elements(_shape(lines, name))):
+            assert name in writes or opcode == "bitcast", line
+    if sweep == "lbnl_sweep":
+        assert {m: len(u) for m, u in updates.items()} == {0: 1, 1: 1, 2: 1, 3: 1, 4: 0}
+        assert compiled.memory_analysis().temp_size_in_bytes <= 5.3 * 2**30
+    else:
+        assert updates == {0: [], 1: [], 2: []}
